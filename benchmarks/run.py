@@ -12,8 +12,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -1709,44 +1707,29 @@ def bench_fig_health():
         transport.close()
 
 
-_CACHE_PROBE = """
-import os, numpy as np
-import jax, jax.numpy as jnp
-from repro.core.backend import ArrayBackend
-from repro.core.compile_cache import CompileCache
-
-def app(x):
-    w = jnp.full((x.shape[-1], x.shape[-1]), 0.01, x.dtype)
-    for _ in range(8):
-        x = jnp.tanh(x @ w) + x * 0.1
-    return x.sum(-1)
-
-jnp.zeros(1).block_until_ready()   # runtime init: not a compile cost
-be = ArrayBackend(cache=CompileCache(cache_dir=os.environ["PROBE_DIR"]))
-x = np.ones((64, 128), np.float32)
-out, rec = be.launch(app, x, 64)
-print(f"T_SCHEDULE={rec.t_schedule:.6f}")
-print(f"SOURCE={rec.extra['compile_source']}")
-"""
-
-
 def bench_persistent_compile_cache():
-    """Cold vs warm *process*: the persistent AOT cache must let a second
-    process skip trace+compile entirely (the launch-side analogue of the
-    paper's pre-staged Wine environment)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(root, "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env["PROBE_DIR"] = tempfile.mkdtemp(prefix="repro-aot-persist-")
+    """Cold vs warm cache: a fresh ``CompileCache`` over the same directory
+    (what a later process sees) must skip trace+compile entirely — the
+    launch-side analogue of the paper's pre-staged Wine environment. One
+    process: on a chip, a child process could not reach the device this
+    one already holds."""
+    from repro.core.backend import ArrayBackend
+    from repro.core.compile_cache import CompileCache
+
+    def app(x):
+        w = jnp.full((x.shape[-1], x.shape[-1]), 0.01, x.dtype)
+        for _ in range(8):
+            x = jnp.tanh(x @ w) + x * 0.1
+        return x.sum(-1)
+
+    jnp.zeros(1).block_until_ready()   # runtime init: not a compile cost
+    d = tempfile.mkdtemp(prefix="repro-aot-persist-")
+    x = np.ones((64, 128), np.float32)
 
     def probe():
-        out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
-                             capture_output=True, text=True, check=True,
-                             cwd=root)
-        kv = dict(l.split("=", 1) for l in out.stdout.strip().splitlines()
-                  if "=" in l)
-        return float(kv["T_SCHEDULE"]), kv["SOURCE"]
+        be = ArrayBackend(cache=CompileCache(cache_dir=d))
+        _, rec = be.launch(app, x, 64)
+        return rec.t_schedule, rec.extra["compile_source"]
 
     t_cold, src_cold = probe()
     t_warm, src_warm = probe()

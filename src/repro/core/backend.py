@@ -50,6 +50,13 @@ def _tree_ready(tree: Any) -> bool:
                if hasattr(l, "is_ready"))
 
 
+def _pad_rows(x: Any, pad: int) -> Any:
+    """``x`` with ``pad`` zero rows appended on the task axis (host arrays
+    stay on the host)."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    return xp.concatenate([x, xp.zeros((pad,) + x.shape[1:], x.dtype)])
+
+
 def concat_outputs(parts: list) -> Any:
     """Concatenate per-wave (or per-shard) outputs along the task axis —
     the ONE merge semantics shared by the policy driver's wave concat and
@@ -254,6 +261,14 @@ class ArrayBackend:
                  cache: Optional[CompileCache] = None,
                  donate: bool = False,
                  target_first_result_s: Optional[float] = None):
+        if mesh is not None:
+            # the wave program shards only its task axis and lets the
+            # compiler place the rest (Auto axes; ``jax.make_mesh`` makes
+            # Explicit ones, under which trimming a padded wave is an error)
+            mesh = jax.sharding.Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(
+                    mesh.axis_names))
         self.mesh = mesh
         self.task_axis = task_axis
         self.inner_lanes = inner_lanes
@@ -307,8 +322,14 @@ class ArrayBackend:
         else:
             mapped = jax.vmap(fn)
         in_shardings = None
-        if (self.mesh is not None
-                and outer % self.mesh.shape[self.task_axis] == 0):
+        if self.mesh is not None:
+            # a wave the task axis does not divide is padded up to it (the
+            # pad lanes' outputs are dropped): every wave runs on the mesh
+            pad = (-outer) % self.mesh.shape[self.task_axis]
+            if pad:
+                chunk = jax.tree_util.tree_map(
+                    lambda x: _pad_rows(x, pad), chunk)
+                outer += pad
             sh = jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec(self.task_axis))
             in_shardings = jax.tree_util.tree_map(lambda _: sh, chunk)
@@ -342,7 +363,9 @@ class ArrayBackend:
         out = compiled(staged)
         if inner > 1:                 # un-nest node/core axes (async too)
             out = jax.tree_util.tree_map(
-                lambda x: x.reshape((n,) + x.shape[2:]), out)
+                lambda x: x.reshape((outer * inner,) + x.shape[2:]), out)
+        if outer * inner != n:        # drop the mesh-padding lanes
+            out = jax.tree_util.tree_map(lambda x: x[:n], out)
         rec.t_dispatch = time.perf_counter() - t0
         return WaveHandle(out, rec, t0)
 

@@ -32,13 +32,26 @@ import pickle
 import re
 import tempfile
 import threading
+import time
 from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
 
-_DEFAULT_DIR_ENV = "REPRO_COMPILE_CACHE_DIR"
 _MAX_BYTES_ENV = "REPRO_COMPILE_CACHE_MAX_BYTES"
+# the fixed in-checkout home of the disk tier (listed in .gitignore)
+_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".aot_cache")
+
+
+def default_cache_dir() -> str:
+    """Where a ``CompileCache`` spills unless told otherwise: the
+    directory ``$JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX
+    keeps its own persistent cache there too), else ``.aot_cache`` at the
+    root of the checkout. A fixed path: the directory is part of what a
+    later process must find again."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_DIR
 
 _VERSION_TAG: Optional[str] = None
 
@@ -180,7 +193,7 @@ _TREE_SALT: Optional[str] = None
 
 
 def _source_tree_salt() -> str:
-    """Digest of the ``repro`` package's source files (path, mtime, size),
+    """Digest of the ``repro`` package's source files (path and content),
     computed once per process and folded into every fingerprint.
 
     The static context walk above sees the launched function, its closure/
@@ -188,22 +201,21 @@ def _source_tree_salt() -> str:
     see an edit buried deeper in the call graph (fn -> g -> h). Rather
     than serve a stale persisted executable after such an edit, ANY change
     to the package's sources invalidates the disk tier (a conservative
-    miss, never a wrong hit). Callees in modules outside ``repro`` remain
-    the caller's responsibility (pass a version via ``extras``)."""
+    miss, never a wrong hit). Content, not mtimes: a copied checkout of
+    the same sources keeps its keys. Callees in modules outside ``repro``
+    remain the caller's responsibility (pass a version via ``extras``)."""
     global _TREE_SALT
     if _TREE_SALT is None:
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         h = hashlib.sha256()
-        for dirpath, _, files in sorted(os.walk(root)):
+        for dirpath, dirs, files in os.walk(root):
+            dirs.sort()
             for f in sorted(files):
                 if f.endswith(".py"):
                     p = os.path.join(dirpath, f)
-                    try:
-                        st = os.stat(p)
-                    except OSError:
-                        continue
-                    h.update(f"{os.path.relpath(p, root)}:"
-                             f"{st.st_mtime_ns}:{st.st_size}".encode())
+                    with open(p, "rb") as fh:
+                        h.update(os.path.relpath(p, root).encode() + b"\0")
+                        h.update(fh.read())
         _TREE_SALT = h.hexdigest()[:16]
     return _TREE_SALT
 
@@ -221,13 +233,29 @@ def fingerprint(fn: Callable, abstract_args: tuple,
     """Content key for one (program, input signature, topology) triple."""
     leaves, treedef = jax.tree_util.tree_flatten(abstractify(abstract_args))
     avals = "|".join(f"{tuple(l.shape)}:{l.dtype}" for l in leaves)
-    mesh_sig = tuple(mesh.shape.items()) if mesh is not None else ()
+    # the devices, not just the shape: two one-chip meshes on different
+    # chips compile to different executables
+    mesh_sig = ((tuple(mesh.shape.items()),
+                 tuple(int(d.id) for d in mesh.devices.flat))
+                if mesh is not None else ())
     blob = "\n".join([
         _source_hash(fn), str(treedef), avals, str(mesh_sig),
         str(tuple(extras)), jax.__version__, jax.default_backend(),
         _source_tree_salt(),
     ])
     return hashlib.sha256(blob.encode()).hexdigest()
+
+
+def _device_ids(compiled) -> list:
+    """Ids of the devices an executable runs on, in its assignment order:
+    a reload must bind it to the same ones (left alone, it binds to every
+    local device)."""
+    for sh in jax.tree_util.tree_leaves(compiled.input_shardings):
+        mesh = getattr(sh, "mesh", None)
+        if mesh is not None:
+            return [int(d.id) for d in mesh.devices.flat]
+        return sorted(int(d.id) for d in sh.device_set)
+    return [int(jax.devices()[0].id)]
 
 
 # ----------------------------------------------------------------------
@@ -238,7 +266,10 @@ class CompileCache:
     """Two-tier (memory, disk) cache of AOT-compiled executables.
 
     Disk persistence is best-effort: any serialization failure degrades to
-    memory-only caching, never to an error on the launch path.
+    memory-only caching, never to an error on the launch path — but it is
+    counted (``stats["spill_errors"]`` / ``stats["load_errors"]``) and the
+    latest one kept in ``last_error``, so a cache that never persists is
+    visible to whoever prints its stats.
 
     The disk tier is bounded: ``max_bytes`` (default from
     ``REPRO_COMPILE_CACHE_MAX_BYTES``; None = unbounded) caps the dir with
@@ -252,11 +283,8 @@ class CompileCache:
     def __init__(self, cache_dir: Optional[str] = None,
                  persistent: bool = True,
                  max_bytes: Optional[int] = None):
-        if cache_dir is None:
-            cache_dir = os.environ.get(
-                _DEFAULT_DIR_ENV,
-                os.path.join(os.path.expanduser("~"), ".cache", "repro-aot"))
-        self.cache_dir = cache_dir
+        self.cache_dir = cache_dir if cache_dir is not None \
+            else default_cache_dir()
         self.persistent = persistent
         if max_bytes is None:
             env = os.environ.get(_MAX_BYTES_ENV)
@@ -266,8 +294,10 @@ class CompileCache:
         self._lock = threading.Lock()
         self._version_pruned = False
         self.stats = {"mem_hits": 0, "disk_hits": 0, "misses": 0,
-                      "spills": 0, "spill_errors": 0,
-                      "evictions": 0, "version_drops": 0}
+                      "spills": 0, "spill_errors": 0, "load_errors": 0,
+                      "evictions": 0, "version_drops": 0,
+                      "compile_s": 0.0}     # trace+lower+compile wall time
+        self.last_error: Optional[str] = None
 
     # -- tiers ------------------------------------------------------------
     def _path(self, key: str) -> str:
@@ -316,28 +346,35 @@ class CompileCache:
     def _disk_get(self, key: str):
         if not self.persistent:
             return None
+        self._prune_stale_versions()
+        path = self._path(key)
         try:
-            self._prune_stale_versions()
-            path = self._path(key)
             with open(path, "rb") as f:
-                payload = pickle.load(f)
+                blob, in_tree, out_tree, ids = pickle.load(f)
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
-            compiled = deserialize_and_load(*payload)
-            try:
-                os.utime(path)               # refresh LRU recency
-            except OSError:
-                pass                         # read-only dir: still a hit
-            return compiled
-        except Exception:
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in ids])
+        except FileNotFoundError:
+            return None                      # a plain miss
+        except Exception as e:  # noqa: BLE001 — counted; the caller compiles
+            self.stats["load_errors"] += 1
+            self.last_error = f"load {os.path.basename(path)}: {e!r}"
             return None
+        try:
+            os.utime(path)                   # refresh LRU recency
+        except OSError:
+            pass                             # read-only dir: still a hit
+        return compiled
 
     def _disk_put(self, key: str, compiled) -> None:
         if not self.persistent:
             return
         try:
             from jax.experimental.serialize_executable import serialize
-            payload = serialize(compiled)
+            payload = serialize(compiled) + (_device_ids(compiled),)
             os.makedirs(self.cache_dir, exist_ok=True)
             self._prune_stale_versions()
             fd, tmp = tempfile.mkstemp(dir=self.cache_dir, suffix=".tmp")
@@ -346,8 +383,9 @@ class CompileCache:
             os.replace(tmp, self._path(key))     # atomic publish
             self.stats["spills"] += 1
             self._prune_lru()
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — counted; memory tier holds it
             self.stats["spill_errors"] += 1
+            self.last_error = f"spill: {e!r}"
 
     # -- public API -------------------------------------------------------
     def get(self, key: str):
@@ -397,7 +435,10 @@ class CompileCache:
             kwargs["in_shardings"] = in_shardings
         if donate_argnums:
             kwargs["donate_argnums"] = donate_argnums
+        t0 = time.perf_counter()
         compiled = jax.jit(fn, **kwargs).lower(*avals).compile()
+        with self._lock:
+            self.stats["compile_s"] += time.perf_counter() - t0
         self.put(key, compiled)
         return compiled, "compiled"
 

@@ -70,6 +70,7 @@ class MapReduceReport:
     speculative_redispatches: int = 0
     node_failures: int = 0            # waves re-dispatched off dead nodes
     t_reduce: float = 0.0
+    t_first_result: float = 0.0       # call start -> first wave harvested
     t_total: float = 0.0
     autoscale: List[WaveDecision] = field(default_factory=list)
     metrics: dict = field(default_factory=dict)  # registry delta for this call
@@ -239,6 +240,7 @@ class LLMapReduce:
         lanes_ok = getattr(self.backend, "supports_lane_override", False)
         report = MapReduceReport()
         t_all = Timer()
+        t_begin = time.perf_counter()
         wave_times: List[float] = []
         outs: dict = {}
         slots: List[_Slot] = []
@@ -344,6 +346,8 @@ class LLMapReduce:
                               attrs={"wave": slot.wi})
             out, rec = slot.attempts[winner].result()
             now = time.perf_counter()
+            if not report.t_first_result:
+                report.t_first_result = now - t_begin
             dt = now - slot.t_attempt[winner]
             for j, h in enumerate(slot.attempts):
                 if j == winner:

@@ -46,6 +46,15 @@ relays degrade to direct send, never a hang or a silent corrupt stage.
                    the elastic-join path — the agent owns only the
                    scheduler-side channel.
 
+  A node runs on whatever platform JAX picks in its process, and reports
+  it (platform, device kind, device ids) in a HEARTBEAT right after it
+  builds its backend (``NodeRegistry.rollup()[id]["device"]``). Thread
+  nodes split the scheduler process's devices between them. A process or
+  remote node needs a device no other process holds: on a host whose
+  accelerator the scheduler process already uses, run such a fleet on the
+  CPU by starting it with ``JAX_PLATFORMS=cpu`` in the environment
+  (spawned children inherit it) — the CPU-fleet mode the tests use.
+
   transport=InprocTransport   queue pairs (by-reference in one process,
                               mp queues across the spawn boundary).
   transport=SocketTransport   length-prefixed frames over localhost TCP,
@@ -85,14 +94,40 @@ from repro.obs import metrics as _obs
 from repro.obs.trace import TRACER, new_span_id, new_trace_id
 
 
-def _node_cache_dir(node_id: str) -> str:
+def _node_cache_dir(name: str) -> str:
     """Per-node compile-cache dir: each node keeps its own AOT spill tier
-    (on a real cluster this is node-local disk), under the shared base so
-    hermetic test environments stay hermetic."""
-    base = os.environ.get(
-        "REPRO_COMPILE_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-aot"))
-    return os.path.join(base, "nodes", node_id)
+    (on a real cluster this is node-local disk) under the default cache
+    directory. ``name`` must survive a restart of the node (its id for a
+    local node, its host name for a remote one), or a restarted node never
+    finds what it compiled before."""
+    from repro.core.compile_cache import default_cache_dir
+    return os.path.join(default_cache_dir(), "nodes", name)
+
+
+def _node_backend(backend_kind: str, devices: Optional[list], cache: Any,
+                  cache_dir: Optional[str], node_id: str):
+    """A node's own launch backend. A node that owns devices gets a mesh
+    over exactly those (one chip included), so its waves run there and not
+    on the process's default device."""
+    from repro.core.backend import make_backend
+    from repro.core.compile_cache import CompileCache
+    mesh = None
+    if devices:
+        import jax
+        mesh = jax.sharding.Mesh(np.asarray(devices), ("data",))
+    return make_backend(
+        backend_kind, mesh=mesh,
+        cache=cache if cache is not None else CompileCache(
+            cache_dir=cache_dir or _node_cache_dir(node_id)))
+
+
+def _device_report(backend: Any) -> dict:
+    """What a node tells the scheduler about where its waves run."""
+    import jax
+    mesh = getattr(backend, "mesh", None)
+    devs = list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "devices": [int(d.id) for d in devs]}
 
 
 class ShardTask:
@@ -651,16 +686,14 @@ def _worker_loop(node_id: str, channel, ctl: _WorkerCtl,
     # process-hosted node)
     from repro.core.staging import Stager
     if backend is None:
-        from repro.core.backend import make_backend
-        from repro.core.compile_cache import CompileCache
-        mesh = None
-        if devices and len(devices) > 1:
-            import jax
-            mesh = jax.sharding.Mesh(np.asarray(devices), ("data",))
-        backend = make_backend(
-            backend_kind, mesh=mesh,
-            cache=cache if cache is not None else CompileCache(
-                cache_dir=cache_dir or _node_cache_dir(node_id)))
+        backend = _node_backend(backend_kind, devices, cache, cache_dir,
+                                node_id)
+    try:
+        # registration's second half: where this node's waves will run
+        channel.send(HEARTBEAT, {"node": node_id,
+                                 "device": _device_report(backend)})
+    except TransportError:
+        pass
     stager = Stager(busy_clock=ctl.busy_clock)
     assembler = (_ChunkAssembler(node_id, channel, stager, chunk_cache)
                  if stage_dedup else None)
@@ -748,8 +781,6 @@ def _process_main(node_id: str, endpoint: tuple, heartbeat_s: float,
     """Entry point of a process-hosted node: connect first (cheap), beat
     while jax imports, then serve shards until LEAVE or SIGTERM."""
     channel = open_worker_channel(endpoint)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
     # peers can only reach a process-hosted node over TCP; an inproc
     # cache token would not resolve across the spawn boundary
     peer_mode = "tcp" if endpoint[0] == "socket" else None
@@ -840,16 +871,8 @@ class NodeAgent:
             # local imports: a NodeAgent is constructible before jax
             # config (mirrors a node booting before it joins the mesh)
             if backend is None:
-                from repro.core.backend import make_backend
-                from repro.core.compile_cache import CompileCache
-                mesh = None
-                if devices and len(devices) > 1:
-                    import jax
-                    mesh = jax.sharding.Mesh(np.asarray(devices), ("data",))
-                backend = make_backend(
-                    backend_kind, mesh=mesh,
-                    cache=cache if cache is not None else CompileCache(
-                        cache_dir=cache_dir or _node_cache_dir(node_id)))
+                backend = _node_backend(backend_kind, devices, cache,
+                                        cache_dir, node_id)
             self.backend = backend
             self._ctl = _WorkerCtl()
             self._port = self.transport.create(node_id)
@@ -1235,6 +1258,8 @@ class NodeAgent:
             if not self._killed:
                 self.registry.heartbeat(self.node_id)
                 p = frame.payload
+                if isinstance(p, dict) and "device" in p:
+                    self.registry.set_device(self.node_id, p["device"])
                 if isinstance(p, dict) and "m" in p:
                     # metrics piggyback: the node's cumulative snapshot
                     # flew home on the beat — latest wins per node
@@ -1351,7 +1376,9 @@ def _connect_main(argv: Optional[List[str]] = None) -> None:
                         help="node-local launch backend kind")
     parser.add_argument("--heartbeat-s", type=float, default=0.25)
     parser.add_argument("--cache-dir", default=None,
-                        help="node-local AOT compile cache directory")
+                        help="node-local AOT compile cache directory "
+                             "(default: nodes/<node id or host name> under "
+                             "the default compile-cache directory)")
     parser.add_argument("--chunk-cache-bytes", type=int,
                         default=DEFAULT_CHUNK_CACHE_BYTES)
     parser.add_argument("--peer-bind-host", default="0.0.0.0",
@@ -1375,10 +1402,10 @@ def _connect_main(argv: Optional[List[str]] = None) -> None:
     channel = SocketTransport.connect((host, int(port)), node_id,
                                       secret=secret,
                                       capacity=args.capacity)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
+    cache_dir = args.cache_dir or _node_cache_dir(
+        args.node_id or f"remote-{_socket.gethostname()}")
     _worker_loop(node_id, channel, _WorkerCtl(), args.heartbeat_s,
-                 backend_kind=args.backend, cache_dir=args.cache_dir,
+                 backend_kind=args.backend, cache_dir=cache_dir,
                  numpy_out=True, stage_dedup=True,
                  chunk_cache_bytes=args.chunk_cache_bytes,
                  peer_mode="tcp",

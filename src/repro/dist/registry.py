@@ -147,6 +147,15 @@ class NodeRegistry:
             self._version += 1
 
     # -- membership --------------------------------------------------------
+    def set_device(self, node_id: str, device: dict) -> None:
+        """Record where a node's waves run, as the node reported it at
+        registration (``platform``, ``kind``, device ``ids``)."""
+        sh = self._shard(node_id)
+        with sh.lock:
+            info = sh.nodes.get(node_id)
+            if info is not None:
+                info.extra["device"] = dict(device)
+
     def register(self, node_id: str, capacity: int = 1) -> NodeInfo:
         """Admit (or revive) a node. Idempotent: a re-register refreshes
         the lease and capacity — this IS the elastic-join path."""
@@ -411,7 +420,7 @@ class NodeRegistry:
 
     def rollup(self) -> Dict[str, dict]:
         """Per-node summary (state, capacity, dispatched work, failures,
-        measured cost, anomaly verdict)."""
+        measured cost, anomaly verdict, reported device)."""
         self.sweep()
         out: Dict[str, dict] = {}
         for sh in self._shards:
@@ -422,6 +431,7 @@ class NodeRegistry:
                         "waves": i.waves, "instances": i.instances,
                         "failures": i.failures,
                         "health": i.extra.get("health", HEALTHY),
+                        "device": i.extra.get("device"),
                         "cost_per_instance":
                             i.cost.value if i.cost else None}
         return out

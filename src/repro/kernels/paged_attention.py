@@ -12,6 +12,16 @@ block-indexed loads straight from the pool, online-softmax accumulation
 per page block, with dead pages (table entry -1), empty rows (pos -1),
 causality and sliding windows all neutralized in-kernel.
 
+Layout (what Mosaic's tiling rule — the last two block dims divisible by
+(8, 128) or equal to the array's — forces): the pool is head-major,
+``(P, K, ps, D)``, so one grid step's K/V block ``(1, 1, ps, D)`` is one
+page of one kv head with ``(ps, D)`` as its full trailing dims at any page
+size. Queries are regrouped per kv head into ``(B, K, S*G, D)`` rows
+(row ``r`` = query ``r // G``, head ``r % G`` of the group), so both
+matmuls are plain 2-D ``(rows, D) x (ps, D)^T`` and ``(rows, ps) x
+(ps, D)``; positions ride as a ``(1, ps)`` key row and a ``(rows, 1)``
+query column.
+
 One kernel serves decode (S == 1) and prefill (S up to the virtual
 capacity); the grid is (slots, kv_heads, q_blocks, pages_per_slot) with
 the page axis innermost so softmax statistics live in VMEM scratch across
@@ -22,9 +32,11 @@ weight-absorbed decode form — scores are ``q.k + q2.k2`` (= q_abs.ckv +
 q_rope.kr) against the compressed cache — without ever concatenating
 pool-resident leaves.
 
-CPU runs use ``interpret=True`` (numerics validated against
-``ref.paged_attention_ref``); real-TPU lowering shares the roofline
-caveats of ``flash_attention`` (EXPERIMENTS.md §Roofline).
+Numerics follow the XLA gather path (``models.attention._attn_flat``): the
+softmax scale is folded into the queries before the matmul, scores and
+statistics accumulate in float32, and probabilities meet V in V's dtype.
+CPU runs use ``interpret=True`` (validated against
+``ref.paged_attention_ref``).
 """
 from __future__ import annotations
 
@@ -37,10 +49,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# query rows (positions x heads per kv head) in one grid step: bounds the
+# VMEM the q block, the f32 accumulator and the score tile take when a kv
+# head serves many query heads (MLA: 128)
+MAX_ROWS = 1024
 
 
-def _kernel(tbl_ref, *refs, scale: float, causal: bool, window, cap,
-            bq: int, ps: int, has_q2: bool):
+def _kernel(tbl_ref, *refs, causal: bool, window, cap, has_q2: bool):
     if has_q2:
         q_ref, k_ref, v_ref, kpos_ref, qpos_ref, q2_ref, k2_ref = refs[:7]
         o_ref, m_sc, l_sc, acc_sc = refs[7:]
@@ -61,47 +76,38 @@ def _kernel(tbl_ref, *refs, scale: float, causal: bool, window, cap,
 
     @pl.when(t >= 0)
     def _block():
-        G = q_ref.shape[3]
-        q = q_ref[0, :, 0].astype(jnp.float32)               # (bq, G, Dk)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, Dk)
-        s = jax.lax.dot_general(                             # (bq, G, ps)
-            q, k, (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        nt = (((1,), (1,)), ((), ()))                        # a @ b.T
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], nt,  # (R, ps)
+                                preferred_element_type=jnp.float32)
         if has_q2:
-            q2 = q2_ref[0, :, 0].astype(jnp.float32)
-            k2 = k2_ref[0, :, 0].astype(jnp.float32)
-            s += jax.lax.dot_general(
-                q2, k2, (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        s = s * scale
+            s += jax.lax.dot_general(q2_ref[0, 0], k2_ref[0, 0], nt,
+                                     preferred_element_type=jnp.float32)
         if cap is not None:
             s = cap * jnp.tanh(s / cap)
-        kp = kpos_ref[0]                                     # (ps,)
-        qp = qpos_ref[0]                                     # (bq,)
-        mask = (kp >= 0)[None, None, :]
+        kp = kpos_ref[0]                                     # (1, ps)
+        qp = qpos_ref[0]                                     # (R, 1)
+        mask = jnp.broadcast_to(kp >= 0, s.shape)
         if causal:
-            mask &= kp[None, None, :] <= qp[:, None, None]
+            mask &= kp <= qp
         if window is not None:
-            mask &= (qp[:, None, None] - kp[None, None, :]) < window
-        mask = jnp.broadcast_to(mask, (bq, G, ps))
+            mask &= (qp - kp) < window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_sc[...]                                   # (bq, G)
-        m_new = jnp.maximum(m_prev, s.max(axis=2))
+        m_prev = m_sc[...]                                   # (R, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask, p, 0.0)
-        l_sc[...] = l_sc[...] * alpha + p.sum(axis=2)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_sc[...] = l_sc[...] * alpha + p.sum(axis=1, keepdims=True)
         m_sc[...] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (ps, Dv)
-        acc_sc[...] = acc_sc[...] * alpha[..., None] + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((), ())),
+        v = v_ref[0, 0]                                      # (ps, Dv)
+        acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
     def _finish():
         l = l_sc[...]
         l = jnp.where(l > 0, l, 1.0)                         # dead slot -> 0
-        o_ref[0, :, 0] = (acc_sc[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q, k, v, kpos, tables, q_pos, *, q2=None, k2=None,
@@ -111,82 +117,91 @@ def paged_attention(q, k, v, kpos, tables, q_pos, *, q2=None, k2=None,
     """Attention over pool-resident KV via an in-kernel page-table walk.
 
     q:      (B, S, H, Dk)   queries (decode: S == 1)
-    k:      (P, ps, K, Dk)  pooled keys   — P pages of ps rows, H % K == 0
-    v:      (P, ps, K, Dv)  pooled values
+    k:      (P, K, ps, Dk)  pooled keys, head-major — P pages of ps rows,
+                            H % K == 0
+    v:      (P, K, ps, Dv)  pooled values
     kpos:   (P, ps) int32   absolute position per pool row (-1 = empty)
     tables: (B, npps) int32 page table per slot (-1 = unallocated)
     q_pos:  (B, S) int32    absolute query positions (-1 = pad row)
     q2/k2:  optional second score component (MLA absorbed form);
-            q2: (B, S, H, Dk2), k2: (P, ps, K, Dk2)
+            q2: (B, S, H, Dk2), k2: (P, K, ps, Dk2)
+
+    At most ``block_q`` query positions form one grid step, fewer when
+    ``block_q * H / K`` would pass ``MAX_ROWS`` rows.
 
     Returns (B, S, H, Dv) in v.dtype. A slot whose table is all -1 (or a
     pad query row) gets exact zeros.
     """
     B, S, H, Dk = q.shape
-    P, ps, K, _ = k.shape
+    P, K, ps, _ = k.shape
     Dv = v.shape[-1]
-    assert H % K == 0, (H, K)
+    if H % K:
+        raise ValueError(f"{H} query heads do not group over {K} kv heads")
     G = H // K
     npps = tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(Dk + (q2.shape[-1] if q2 is not None else 0))
 
-    bq = min(block_q, S)
+    bq = min(block_q, S, max(1, MAX_ROWS // G))
+    if bq < S:                      # a partial block's rows must tile by 8
+        m = 8 // math.gcd(G, 8)
+        bq = max(m, bq - bq % m)
     pad_q = (-S) % bq
-    q5 = q.reshape(B, S, K, G, Dk)
+    Sp = S + pad_q
+    nq = Sp // bq
+    R = bq * G                                   # query rows per grid step
+
+    def rows(x):
+        """(B, S, H, D) -> scaled (B, K, Sp*G, D) rows grouped per kv head."""
+        x = (x.astype(jnp.float32) * scale).astype(x.dtype)
+        x = x.reshape(B, S, K, G, x.shape[-1])
+        if pad_q:
+            x = jnp.pad(x, ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
+        return x.transpose(0, 2, 1, 3, 4).reshape(B, K, Sp * G, x.shape[-1])
+
     if pad_q:
-        q5 = jnp.pad(q5, ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
         q_pos = jnp.pad(q_pos, ((0, 0), (0, pad_q)), constant_values=-1)
-    nq = q5.shape[1] // bq
+    qp_rows = jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)[..., None]
     grid = (B, K, nq, npps)
 
-    def _page(b, h, i, j, tbl):
+    def _page(b, j, tbl):
         return jnp.maximum(tbl[b, j], 0)       # -1 clamps; masked in-kernel
 
+    def row_spec(d):
+        return pl.BlockSpec((1, 1, R, d), lambda b, h, i, j, tbl: (b, h, i, 0))
+
+    def page_spec(d):
+        return pl.BlockSpec((1, 1, ps, d),
+                            lambda b, h, i, j, tbl: (_page(b, j, tbl), h, 0, 0))
+
     in_specs = [
-        pl.BlockSpec((1, bq, 1, G, Dk),
-                     lambda b, h, i, j, tbl: (b, i, h, 0, 0)),
-        pl.BlockSpec((1, ps, 1, Dk),
-                     lambda b, h, i, j, tbl: (_page(b, h, i, j, tbl), 0, h, 0)),
-        pl.BlockSpec((1, ps, 1, Dv),
-                     lambda b, h, i, j, tbl: (_page(b, h, i, j, tbl), 0, h, 0)),
-        pl.BlockSpec((1, ps),
-                     lambda b, h, i, j, tbl: (_page(b, h, i, j, tbl), 0)),
-        pl.BlockSpec((1, bq), lambda b, h, i, j, tbl: (b, i)),
+        row_spec(Dk), page_spec(Dk), page_spec(Dv),
+        pl.BlockSpec((1, 1, ps),
+                     lambda b, h, i, j, tbl: (_page(b, j, tbl), 0, 0)),
+        pl.BlockSpec((1, R, 1), lambda b, h, i, j, tbl: (b, i, 0)),
     ]
-    args = [q5, k, v, kpos, q_pos]
+    args = [rows(q), k, v, kpos.reshape(P, 1, ps), qp_rows]
     if q2 is not None:
-        Dk2 = q2.shape[-1]
-        q25 = q2.reshape(B, S, K, G, Dk2)
-        if pad_q:
-            q25 = jnp.pad(q25,
-                          ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
-        in_specs += [
-            pl.BlockSpec((1, bq, 1, G, Dk2),
-                         lambda b, h, i, j, tbl: (b, i, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dk2),
-                         lambda b, h, i, j, tbl:
-                         (_page(b, h, i, j, tbl), 0, h, 0)),
-        ]
-        args += [q25, k2]
+        in_specs += [row_spec(q2.shape[-1]), page_spec(k2.shape[-1])]
+        args += [rows(q2), k2]
 
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal, window=window,
-                          cap=softcap, bq=bq, ps=ps,
+        functools.partial(_kernel, causal=causal, window=window, cap=softcap,
                           has_q2=q2 is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bq, 1, G, Dv),
-                                   lambda b, h, i, j, tbl: (b, i, h, 0, 0)),
+            out_specs=row_spec(Dv),
             scratch_shapes=[
-                pltpu.VMEM((bq, G), jnp.float32),
-                pltpu.VMEM((bq, G), jnp.float32),
-                pltpu.VMEM((bq, G, Dv), jnp.float32),
+                pltpu.VMEM((R, 1), jnp.float32),
+                pltpu.VMEM((R, 1), jnp.float32),
+                pltpu.VMEM((R, Dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nq * bq, K, G, Dv), v.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, Sp * G, Dv), v.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(tables, *args)
-    return out.reshape(B, nq * bq, H, Dv)[:, :S]
+    out = out.reshape(B, K, Sp, G, Dv).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, Sp, H, Dv)[:, :S]

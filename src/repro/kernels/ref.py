@@ -40,20 +40,25 @@ def paged_attention_ref(q, k, v, kpos, tables, q_pos, *, q2=None, k2=None,
     list into a per-slot view (exactly ``models.lm.paged_gather`` for one
     leaf), then run naive masked attention.
 
-    q: (B,S,H,Dk), k/v: (P,ps,K,Dk/Dv), kpos: (P,ps), tables: (B,npps),
+    q: (B,S,H,Dk), k/v: (P,K,ps,Dk/Dv) head-major pool pages, kpos:
+    (P,ps), tables: (B,npps),
     q_pos: (B,S). Optional q2/k2 add a second score component (MLA
     absorbed form). Returns (B,S,H,Dv) in v.dtype.
     """
     B, S, H, Dk = q.shape
-    P, ps, K, _ = k.shape
+    P, K, ps, _ = k.shape
     npps = tables.shape[1]
     vcap = npps * ps
     if scale is None:
         scale = 1.0 / math.sqrt(Dk + (q2.shape[-1] if q2 is not None else 0))
 
     cl = jnp.maximum(tables, 0)
-    kd = jnp.take(k, cl, axis=0).reshape(B, vcap, K, -1)
-    vd = jnp.take(v, cl, axis=0).reshape(B, vcap, K, -1)
+
+    def dense(leaf):                        # (P,K,ps,D) -> (B,vcap,K,D)
+        d = jnp.take(leaf, cl, axis=0)                  # (B,npps,K,ps,D)
+        return d.transpose(0, 1, 3, 2, 4).reshape(B, vcap, K, -1)
+
+    kd, vd = dense(k), dense(v)
     kp = jnp.take(kpos, cl, axis=0).reshape(B, vcap)
     kp = jnp.where(jnp.repeat(tables >= 0, ps, axis=1), kp, -1)
 
@@ -63,7 +68,7 @@ def paged_attention_ref(q, k, v, kpos, tables, q_pos, *, q2=None, k2=None,
     s = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32),
                    kd.astype(jnp.float32))
     if q2 is not None:
-        k2d = jnp.take(k2, cl, axis=0).reshape(B, vcap, K, -1)
+        k2d = dense(k2)
         if K != H:
             k2d = jnp.repeat(k2d, H // K, axis=2)
         s += jnp.einsum("bqhd,bshd->bhqs", q2.astype(jnp.float32),

@@ -57,8 +57,9 @@ def main():
                          "launch-side wave sizing at the same target")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent AOT compile cache dir (default: "
-                         "$REPRO_COMPILE_CACHE_DIR or ~/.cache/repro-aot); "
-                         "a warm dir skips trace+compile entirely")
+                         "$JAX_COMPILATION_CACHE_DIR, else .aot_cache in "
+                         "the checkout); a warm dir skips trace+compile "
+                         "entirely")
     ap.add_argument("--no-cache-spill", action="store_true",
                     help="keep the compile cache in memory only")
     args = ap.parse_args()

@@ -245,7 +245,7 @@ def _ring_update(cache: dict, new: dict, positions: jax.Array) -> dict:
 # ---------------------------------------------------------------------------
 #
 # A paged cache leaf is the POOL's leaf for one scan repeat plus the slots'
-# page tables: {"k": (P, ps, K, D), "v": ..., "pos": (P, ps),
+# page tables: {"k": (P, K, ps, D), "v": ..., "pos": (P, ps),
 # "table": (B, npps)} (MLA: ckv/kr instead of k/v; int8: + k_scale/v_scale).
 # Fresh rows are scattered straight into their pages (no dense intermediate)
 # and attention reads the pool through the table — either by materializing
@@ -254,7 +254,11 @@ def _ring_update(cache: dict, new: dict, positions: jax.Array) -> dict:
 # Row -> page mapping matches ``models.lm.paged_scatter``: virtual row
 # v = pos % vcap lives in page table[v // ps] at offset v % ps; a -1 table
 # entry (stalled/dead slot) or -1 position (pad row) drops the write via an
-# out-of-range page index.
+# out-of-range page index. Leaves with a kv-head axis are stored head-major,
+# (P, K, ps, ...), so one page of one kv head is the contiguous (ps, D)
+# tile the kernel loads; the others (pos, MLA's ckv/kr) are (P, ps, ...).
+
+HEAD_MAJOR = frozenset({"k", "v", "k_scale", "v_scale"})
 
 def _paged_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -273,8 +277,9 @@ def _paged_leaf_update(cache: dict, entries: dict,
     tgt = jnp.where(valid & (page >= 0), page, P)             # OOB drops
     new = dict(cache)
     for k, rows in entries.items():
-        new[k] = cache[k].at[tgt, off].set(rows.astype(cache[k].dtype),
-                                           mode="drop")
+        idx = (tgt, slice(None), off) if k in HEAD_MAJOR else (tgt, off)
+        new[k] = cache[k].at[idx].set(rows.astype(cache[k].dtype),
+                                      mode="drop")
     new["pos"] = cache["pos"].at[tgt, off].set(positions, mode="drop")
     return new
 
@@ -286,11 +291,14 @@ def _paged_leaf_gather(cache: dict):
     B, npps = table.shape
     cl = jnp.maximum(table, 0)
 
-    def g(leaf):
-        d = jnp.take(leaf, cl, axis=0)            # (B, npps, ps, ...)
-        return d.reshape(B, npps * ps, *leaf.shape[2:])
+    def g(leaf, head_major=False):
+        d = jnp.take(leaf, cl, axis=0)            # (B, npps, [K,] ps, ...)
+        if head_major:
+            d = jnp.swapaxes(d, 2, 3)
+        return d.reshape(B, npps * ps, *d.shape[3:])
 
-    dense = {k: g(v) for k, v in cache.items() if k not in ("table", "pos")}
+    dense = {k: g(v, k in HEAD_MAJOR) for k, v in cache.items()
+             if k not in ("table", "pos")}
     kpos = jnp.where(jnp.repeat(table >= 0, ps, axis=1), g(cache["pos"]), -1)
     return dense, kpos
 
@@ -501,12 +509,11 @@ def mla_apply(params: dict, x: jax.Array, spec: AttentionSpec,
         q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, params["w_uk"])
         if cfg.paged_kernel == "pallas":
             from repro.kernels.paged_attention import paged_attention
-            ckv_p = new_cache["ckv"][:, :, None, :]
+            ckv_p = new_cache["ckv"][:, None].astype(q_abs.dtype)
             ctx = paged_attention(
-                q_abs, ckv_p.astype(q_abs.dtype), ckv_p.astype(q_abs.dtype),
+                q_abs, ckv_p, ckv_p,
                 new_cache["pos"], new_cache["table"], positions,
-                q2=q_rope, k2=new_cache["kr"][:, :, None, :].astype(
-                    q_abs.dtype),
+                q2=q_rope, k2=new_cache["kr"][:, None].astype(q_abs.dtype),
                 scale=scale, causal=True, interpret=_paged_interpret())
         else:
             dense, k_pos = _paged_leaf_gather(new_cache)

@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.attention import attn_cache_init
+from repro.models.attention import HEAD_MAJOR, attn_cache_init
 from repro.models.blocks import (block_cache_init, group_apply,
                                  group_cache_init, group_init)
 from repro.models.ssm import ssm_cache_init
@@ -311,7 +311,9 @@ def prefill_batched(params: dict, inputs: dict, cfg: ModelConfig,
 # capacity)`` gives every slot its own ring whether it holds an 8-token or
 # an 800-token request. The paged layout pools that memory: attention cache
 # leaves carry a PAGE axis of ``n_pages`` fixed-size pages — (repeats,
-# n_pages, page_size, ...) — and each slot owns an ordered page list (its
+# n_pages, page_size, ...), head-major (repeats, n_pages, kv_heads,
+# page_size, ...) for the k/v leaves (``attention.HEAD_MAJOR``) — and each
+# slot owns an ordered page list (its
 # page table). Slot b's virtual cache row v lives in page
 # ``tables[b, v // page_size]`` at offset ``v % page_size``; -1 table
 # entries read as empty (pos = -1), so unallocated tail pages cost nothing
@@ -336,7 +338,10 @@ def paged_cache_init(cfg: ModelConfig, slots: int, n_pages: int,
             if b.attn is not None:
                 spec = (b.attn if b.attn.window is None
                         else dataclasses.replace(b.attn, window=None))
-                c["attn"] = attn_cache_init(n_pages, page_size, spec)
+                c["attn"] = {
+                    k: jnp.swapaxes(a, 1, 2) if k in HEAD_MAJOR else a
+                    for k, a in attn_cache_init(n_pages, page_size,
+                                                spec).items()}
             if b.ssm is not None:
                 c["ssm"] = ssm_cache_init(slots, cfg.d_model, b.ssm)
             per_block[str(i)] = c
@@ -379,7 +384,9 @@ def paged_gather(pool: list, tables: jax.Array) -> list:
         valid = jnp.repeat(tables >= 0, ps, axis=1)            # (B, vcap)
         out = {}
         for k, leaf in sub.items():
-            g = jnp.take(leaf, clamped, axis=1)        # (R, B, n_per, ps, …)
+            g = jnp.take(leaf, clamped, axis=1)    # (R, B, n_per, [K,] ps, …)
+            if k in HEAD_MAJOR:
+                g = jnp.swapaxes(g, 3, 4)
             g = g.reshape(g.shape[0], B, n_per * ps, *g.shape[4:])
             if k == "pos":
                 g = jnp.where(valid[None], g, -1)
@@ -422,9 +429,14 @@ def paged_scatter(pool: list, dense: list, tables: jax.Array,
         tgt = jnp.where(ok, page, n_pages)                        # OOB drops
         out = {}
         for k, pl in pool_sub.items():
-            rows = _rows_at(dense_sub[k], j)
-            out[k] = pl.at[:, tgt, off].set(rows.astype(pl.dtype),
-                                            mode="drop")
+            rows = _rows_at(dense_sub[k], j)                  # (R, B, W, …)
+            if k in HEAD_MAJOR:
+                # non-adjacent index arrays put their (B, W) dims first
+                out[k] = pl.at[:, tgt, :, off].set(
+                    jnp.moveaxis(rows, 0, 2).astype(pl.dtype), mode="drop")
+            else:
+                out[k] = pl.at[:, tgt, off].set(rows.astype(pl.dtype),
+                                                mode="drop")
         return out
 
     def ssm_fn(pool_sub, dense_sub):

@@ -58,7 +58,7 @@ from __future__ import annotations
 import hashlib
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -117,6 +117,10 @@ class _EngineBase:
         self.backend = backend if backend is not None else ArrayBackend()
         self.scheduler = scheduler if scheduler is not None \
             else AdmissionScheduler()
+        # called as logit_sink(request, logits_row) for every row of logits
+        # a request gets (its prefill, then each decode step): lets a
+        # caller check one attention path against another on live traffic
+        self.logit_sink: Optional[Callable[[Request, jax.Array], None]] = None
         self.tokens = jnp.zeros((slots, 1), jnp.int32)
         self.pos = jnp.zeros((slots, 1), jnp.int32)
         self.active: List[Optional[Request]] = [None] * slots
@@ -171,8 +175,13 @@ class _EngineBase:
     def _pre_step(self) -> None:
         """Hook before a decode step (page growth for the paged engine)."""
 
-    def _step_executable(self) -> Tuple[jax.Array, None]:
+    def _step_executable(self) -> Tuple[np.ndarray, jax.Array]:
+        """Run one decode step -> (next token per slot, logits)."""
         raise NotImplementedError
+
+    def _emit(self, req: Request, row: jax.Array) -> None:
+        if self.logit_sink is not None:
+            self.logit_sink(req, row)
 
     def _release_slot(self, i: int) -> None:
         self.active[i] = None
@@ -196,12 +205,13 @@ class _EngineBase:
 
     def step(self) -> None:
         """One batched decode step across all slots."""
-        nxt = self._step_executable()
+        nxt, logits = self._step_executable()
         now = time.perf_counter()
         self.stats["steps"] += 1
         for i, req in enumerate(self.active):
             if req is None or i in self._stalled:
                 continue
+            self._emit(req, logits[i, 0])
             req.out.append(int(nxt[i]))
             self.stats["decoded"] += 1
             if req.t_first is None:
@@ -305,6 +315,7 @@ class ServeEngine(_EngineBase):
                 # the scan-stack axis)
                 self.caches = jax.tree_util.tree_map(
                     lambda d, s: jax.vmap(put)(d, s), self.caches, caches)
+                self._emit(req, logits[0, -1])
                 tok = int(jnp.argmax(logits[0, -1]))
                 req.out.append(tok)
                 req.t_first = time.perf_counter()
@@ -336,7 +347,7 @@ class ServeEngine(_EngineBase):
         nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
         self.tokens = nxt[:, None]
         self.pos = self.pos + 1
-        return np.asarray(nxt)
+        return np.asarray(nxt), logits
 
 
 # ----------------------------------------------------------------------
@@ -370,10 +381,10 @@ class PagedServeEngine(_EngineBase):
     shapes): the compiled step gathers each slot's pages into exactly the
     dense view ``decode_step`` always ran on. ``kernel="pallas"`` keeps
     the same math (online softmax over the same masked rows) without ever
-    materializing that view — greedy tokens match the gather path on
-    bounded horizons and logits agree to the last bf16 bit (the two paths
-    reduce in different orders, so 1-ulp wobble is the contract, not
-    bitwise float equality; see EXPERIMENTS.md fig_serve_kernel).
+    materializing that view. The two paths sum the softmax in different
+    orders, so logits agree within a few bf16 ulps, not bit for bit, and
+    greedy tokens may part where two logits tie within that
+    (``tests/test_paged_attention.py``, ``chip_smoke.py``).
     """
 
     def __init__(self, cfg: ModelConfig, params, slots: int = 8,
@@ -444,7 +455,9 @@ class PagedServeEngine(_EngineBase):
                     continue
                 for name, leaf in sub.items():
                     R = leaf.shape[0]
-                    tail = int(np.prod(leaf.shape[3:])) if leaf.ndim > 3 else 1
+                    # per-row elements: page leaves are (R, P, ps, ...) or
+                    # head-major (R, P, K, ps, ...)
+                    tail = int(np.prod(leaf.shape[2:])) // self.pool.page_size
                     item = np.dtype(leaf.dtype).itemsize
                     dense += R * self.slots * vcap * tail * item
                     if name != "pos":
@@ -730,6 +743,7 @@ class PagedServeEngine(_EngineBase):
         self.stats["prefix_hits"] += 1
         if _obs.REGISTRY.enabled:
             self._m_phit.inc()
+        self._emit(req, logits[0, -1])
         tok = int(jnp.argmax(logits[0, -1]))
         req.out.append(tok)
         req.t_first = time.perf_counter()
@@ -778,6 +792,7 @@ class PagedServeEngine(_EngineBase):
         first = np.asarray(jnp.argmax(logits[:, -1], -1), np.int64)
         now = time.perf_counter()
         for r, (slot, req) in enumerate(placed):
+            self._emit(req, logits[r, -1])
             tok = int(first[r])
             req.out.append(tok)
             req.t_first = now
@@ -885,7 +900,7 @@ class PagedServeEngine(_EngineBase):
         else:
             self.tokens = nxt[:, None]
             self.pos = self.pos + 1
-        return np.asarray(nxt)
+        return np.asarray(nxt), logits
 
     def pool_stats(self) -> Dict[str, float]:
         s = dict(self.pool.stats)

@@ -1,6 +1,8 @@
 """LaunchBackend protocol contract: dispatch/poll/result lifecycle, output
 equivalence across serial/array/pipelined, pipelining depth, donation
 gating, and the launcher<->serve shared compile cache."""
+import tempfile
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -60,24 +62,26 @@ def test_factory_rejects_unknown_kind():
 
 @given(n=st.integers(1, 48))
 @settings(max_examples=10, deadline=None)
-def test_backend_outputs_identical(n, tmp_path):
-    """The tentpole contract: every backend computes the same launch."""
-    cache = CompileCache(cache_dir=str(tmp_path / "aot"))
+def test_backend_outputs_identical(n):
+    """The tentpole contract: every backend computes the same launch.
+    Each example gets a fresh cache dir of its own (a function-scoped
+    fixture would be shared across hypothesis examples)."""
     inputs = np.random.default_rng(n).standard_normal((n, 8)).astype(
         np.float32)
     expect = inputs.sum(-1) * 3.0
-    backends = _backends(cache)
-    try:
-        for be in backends:
-            out, rec = be.launch(app, inputs, n)
-            got = (np.asarray([np.asarray(o) for o in out])
-                   if isinstance(out, list) else np.asarray(out))
-            np.testing.assert_allclose(got, expect, rtol=1e-5, atol=1e-4,
-                                       err_msg=be.name)
-            assert rec.n_instances == n
-            assert rec.t_first_result > 0.0
-    finally:
-        _close_all(backends)
+    with tempfile.TemporaryDirectory(prefix="repro-aot-") as d:
+        backends = _backends(CompileCache(cache_dir=d))
+        try:
+            for be in backends:
+                out, rec = be.launch(app, inputs, n)
+                got = (np.asarray([np.asarray(o) for o in out])
+                       if isinstance(out, list) else np.asarray(out))
+                np.testing.assert_allclose(got, expect, rtol=1e-5,
+                                           atol=1e-4, err_msg=be.name)
+                assert rec.n_instances == n
+                assert rec.t_first_result > 0.0
+        finally:
+            _close_all(backends)
 
 
 def test_wavehandle_lifecycle(cache):

@@ -115,3 +115,56 @@ def test_memory_tier_unaffected_by_budget(tmp_path, persistent):
     cache.compile(_fn(1), X, extras=("m",))
     _, src = cache.compile(_fn(1), X, extras=("m",))
     assert src == "memory"
+
+
+def test_default_dir_is_jax_cache_dir_or_checkout(monkeypatch):
+    from repro.core import compile_cache as cc
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/jcc")
+    assert cc.default_cache_dir() == "/somewhere/jcc"
+    assert CompileCache().cache_dir == "/somewhere/jcc"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.default_cache_dir() == os.path.join(root, ".aot_cache")
+
+
+def test_source_salt_keys_on_content_not_mtime(tmp_path, monkeypatch):
+    """A copied checkout (new mtimes, same sources) keeps its keys; an
+    edited source changes them."""
+    import shutil
+    from repro.core import compile_cache as cc
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(cc.__file__)))
+    copy = tmp_path / "repro"
+    shutil.copytree(pkg, copy, ignore=shutil.ignore_patterns("__pycache__"))
+
+    def salt(root):
+        monkeypatch.setattr(cc, "_TREE_SALT", None)
+        monkeypatch.setattr(cc, "__file__",
+                            str(root / "core" / "compile_cache.py"))
+        return cc._source_tree_salt()
+
+    original = salt(copy)
+    for p in copy.rglob("*.py"):
+        os.utime(p, (1, 1))
+    assert salt(copy) == original
+    with open(copy / "core" / "backend.py", "a") as f:
+        f.write("\n# edit\n")
+    assert salt(copy) != original
+
+
+def test_load_and_spill_errors_are_counted(tmp_path):
+    d = str(tmp_path / "aot")
+    cache = CompileCache(cache_dir=d)
+    cache.compile(_fn(1), X, extras=("e",))
+    (name,) = _aotx_files(d)
+    with open(os.path.join(d, name), "wb") as f:
+        f.write(b"not a pickle")
+    fresh = CompileCache(cache_dir=d)
+    _, src = fresh.compile(_fn(1), X, extras=("e",))
+    assert src == "compiled"              # a broken spill is recompiled
+    assert fresh.stats["load_errors"] == 1
+    assert fresh.last_error.startswith("load ")
+    blocked = CompileCache(cache_dir=str(tmp_path / "file"))
+    (tmp_path / "file").write_text("a file where the dir should be")
+    blocked.compile(_fn(2), X, extras=("e",))
+    assert blocked.stats["spill_errors"] == 1
+    assert blocked.last_error.startswith("spill")

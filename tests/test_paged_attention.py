@@ -37,8 +37,8 @@ def _mk(seed, B=2, S=1, H=4, K=2, Dk=16, Dv=16, P=12, ps=4, npps=4,
         filled=None, permute=True, q2dim=None):
     """Random paged-attention problem with a fragmented, permuted pool."""
     r = np.random.default_rng(seed)
-    k = r.standard_normal((P, ps, K, Dk), np.float32)
-    v = r.standard_normal((P, ps, K, Dv), np.float32)
+    k = r.standard_normal((P, K, ps, Dk), np.float32)
+    v = r.standard_normal((P, K, ps, Dv), np.float32)
     order = r.permutation(P) if permute else np.arange(P)
     tables = np.full((B, npps), -1, np.int32)
     kpos = np.full((P, ps), -1, np.int32)
@@ -55,7 +55,7 @@ def _mk(seed, B=2, S=1, H=4, K=2, Dk=16, Dv=16, P=12, ps=4, npps=4,
     q2 = k2 = None
     if q2dim:
         q2 = r.standard_normal((B, S, H, q2dim), np.float32)
-        k2 = r.standard_normal((P, ps, K, q2dim), np.float32)
+        k2 = r.standard_normal((P, K, ps, q2dim), np.float32)
     to = jnp.asarray
     return (to(q), to(k), to(v), to(kpos, jnp.int32), to(tables, jnp.int32),
             to(q_pos, jnp.int32), (to(q2) if q2 is not None else None),
@@ -372,10 +372,22 @@ def test_preemption_heavy_mixed_run_stays_clean(qwen):
     assert len(done) == len(reqs)
 
 
+# Pallas vs gather logit tolerance: the kernel's online softmax sums in
+# another order than the gather path's dense softmax, so an attention
+# output may round to a neighbouring bf16 value in any layer; 4 bf16 ulps
+# at |logit| in [1, 2) bounds what that moves a logit of the smoke model.
+_LOGIT_TOL = 4 * 2.0 ** -7
+
+
 def test_engine_tokens_identical_dense_gather_pallas(qwen):
     """One short trace through all three serving paths — the fixed-ring
-    dense engine, the paged gather engine, and the paged in-kernel
-    engine (interpret mode off-TPU) — must emit identical tokens."""
+    dense engine, the paged gather engine, and the paged in-kernel engine
+    (interpret mode off-TPU). Every logits row a request gets (prefill,
+    then each decode step) matches the gather engine's: bit for bit for
+    the dense engine, which runs the same dense view, and within
+    ``_LOGIT_TOL`` for the kernel. Greedy tokens may part only where the
+    gather logits hold a top-2 tie within that tolerance; past that
+    point the two histories differ and are not compared."""
     from repro.serve.engine import ServeEngine
     cfg, params = qwen
 
@@ -384,7 +396,7 @@ def test_engine_tokens_identical_dense_gather_pallas(qwen):
         return [Request(rid=i, prompt=r.integers(1, cfg.vocab, 6 + 2 * i),
                         max_new=3) for i in range(3)]
 
-    outs = {}
+    runs = {}
     for name, mk in (
             ("dense", lambda: ServeEngine(cfg, params, slots=2,
                                           capacity=16)),
@@ -397,7 +409,24 @@ def test_engine_tokens_identical_dense_gather_pallas(qwen):
                                                 pages_per_slot=4,
                                                 kernel="pallas"))):
         t = trace()
-        mk().run(t, max_steps=500)
+        logits = {r.rid: [] for r in t}
+        eng = mk()
+        eng.logit_sink = lambda req, row, lg=logits: lg[req.rid].append(
+            np.asarray(row, np.float32))
+        eng.run(t, max_steps=500)
         assert all(r.done for r in t)
-        outs[name] = [r.out for r in t]
-    assert outs["dense"] == outs["gather"] == outs["pallas"], outs
+        assert all(len(logits[r.rid]) == len(r.out) == 3 for r in t)
+        runs[name] = (t, logits)
+
+    ref_t, ref_lg = runs["gather"]
+    for name, tol in (("dense", 0.0), ("pallas", _LOGIT_TOL)):
+        t, lg = runs[name]
+        for r, r0 in zip(t, ref_t):
+            for step, (a, b) in enumerate(zip(lg[r.rid], ref_lg[r0.rid])):
+                np.testing.assert_allclose(
+                    a, b, rtol=0, atol=tol, err_msg=f"{name} rid={r.rid} "
+                    f"step={step}")
+                if r.out[step] != r0.out[step]:
+                    top2 = np.sort(b)[-2:]
+                    assert top2[1] - top2[0] <= tol, (name, r.rid, step)
+                    break

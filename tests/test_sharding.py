@@ -12,15 +12,10 @@ from repro.sharding.partition import (ACT_RULES, PARAM_RULES, cache_sharding,
 
 
 def mesh2(data=4, model=2):
-    # build a logical mesh over repeated devices is not allowed; use a
-    # small abstract mesh via AbstractMesh for spec resolution tests.
-    # AbstractMesh's signature changed across jax versions: 0.4.x takes
-    # ((name, size), ...), newer takes (sizes, names).
+    # spec resolution needs only axis names and sizes: an abstract mesh
+    # stands in for 8 devices this process does not have
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("data", data), ("model", model)))
-    except TypeError:
-        return AbstractMesh((data, model), ("data", "model"))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 def test_divisible_dims_get_sharded():
