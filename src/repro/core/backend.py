@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.compile_cache import CompileCache, default_cache
 from repro.core.telemetry import LaunchRecord, Timer
+from repro.obs.trace import TRACER
 
 
 def _tree_ready(tree: Any) -> bool:
@@ -117,18 +118,19 @@ class WaveHandle:
     def result(self) -> tuple:
         """Block until the wave completes; returns (out, LaunchRecord)."""
         if not self._harvested:
-            leaves = jax.tree_util.tree_leaves(self.out)
-            if self._t_first is None and leaves:
-                first = leaves[0]
-                if hasattr(first, "block_until_ready"):
-                    first.block_until_ready()
-                self._t_first = time.perf_counter() - self.t0
-            jax.block_until_ready(self.out)
-            self.rec.t_spawn = time.perf_counter() - self.t0
-            self.rec.t_first_result = (self._t_first
-                                       if self._t_first is not None
-                                       else self.rec.t_spawn)
-            self._harvested = True
+            with TRACER.child("backend.result"):
+                leaves = jax.tree_util.tree_leaves(self.out)
+                if self._t_first is None and leaves:
+                    first = leaves[0]
+                    if hasattr(first, "block_until_ready"):
+                        first.block_until_ready()
+                    self._t_first = time.perf_counter() - self.t0
+                jax.block_until_ready(self.out)
+                self.rec.t_spawn = time.perf_counter() - self.t0
+                self.rec.t_first_result = (self._t_first
+                                           if self._t_first is not None
+                                           else self.rec.t_spawn)
+                self._harvested = True
         return self.out, self.rec
 
     def abandon(self):
@@ -347,27 +349,32 @@ class ArrayBackend:
         as the program is submitted; the WaveHandle's outputs are futures.
         ``inner_lanes`` overrides the backend default for THIS wave (the
         autoscaling controller re-plans the node/core fan-out per wave)."""
-        rec = LaunchRecord(self.name, n)
-        t = Timer()
-        compiled, source, staged, plan = self._compile_wave(
-            fn, chunk, n, inner_lanes)
-        outer, inner, fell_back, requested = plan
-        rec.t_schedule = t.lap()      # the ONE scheduler interaction
-        rec.extra["compile_source"] = source
-        rec.extra["compile_cached"] = source != "compiled"
-        if fell_back:
-            rec.extra["inner_lanes_fallback"] = {
-                "requested": requested, "wave": n, "used": (outer, inner)}
-        rec.fanout = {"sched": 1, "node": outer, "core": inner}
-        t0 = time.perf_counter()
-        out = compiled(staged)
-        if inner > 1:                 # un-nest node/core axes (async too)
-            out = jax.tree_util.tree_map(
-                lambda x: x.reshape((outer * inner,) + x.shape[2:]), out)
-        if outer * inner != n:        # drop the mesh-padding lanes
-            out = jax.tree_util.tree_map(lambda x: x[:n], out)
-        rec.t_dispatch = time.perf_counter() - t0
-        return WaveHandle(out, rec, t0)
+        with TRACER.child("dispatch", where="driver", attrs={"n": n}):
+            rec = LaunchRecord(self.name, n)
+            t = Timer()
+            with TRACER.child("backend.compile_lookup"):
+                compiled, source, staged, plan = self._compile_wave(
+                    fn, chunk, n, inner_lanes)
+            outer, inner, fell_back, requested = plan
+            rec.t_schedule = t.lap()      # the ONE scheduler interaction
+            rec.extra["compile_source"] = source
+            rec.extra["compile_cached"] = source != "compiled"
+            if fell_back:
+                rec.extra["inner_lanes_fallback"] = {
+                    "requested": requested, "wave": n,
+                    "used": (outer, inner)}
+            rec.fanout = {"sched": 1, "node": outer, "core": inner}
+            t0 = time.perf_counter()
+            with TRACER.child("backend.enqueue"):
+                out = compiled(staged)
+                if inner > 1:             # un-nest node/core axes (async)
+                    out = jax.tree_util.tree_map(
+                        lambda x: x.reshape((outer * inner,) + x.shape[2:]),
+                        out)
+                if outer * inner != n:    # drop the mesh-padding lanes
+                    out = jax.tree_util.tree_map(lambda x: x[:n], out)
+            rec.t_dispatch = time.perf_counter() - t0
+            return WaveHandle(out, rec, t0)
 
     def launch(self, fn: Callable, inputs: Any, n: int) -> tuple:
         return self.dispatch(fn, inputs, n).result()

@@ -273,7 +273,8 @@ class LLMapReduce:
             else:
                 w = wave
             hi = min(lo + w, n)
-            chunk = load(lo, hi)
+            with TRACER.span("llmr.load"):
+                chunk = load(lo, hi)
             lanes = lanes if (lanes and lanes_ok) else None
             kw = {"inner_lanes": lanes} if lanes else {}
             t0 = time.perf_counter()
@@ -424,11 +425,12 @@ class LLMapReduce:
             wake = getattr(self.backend, "wave_event", None)
 
             def _pause(seconds: float) -> None:
-                if wake is not None:
-                    wake.wait(timeout=seconds)
-                    wake.clear()
-                else:
-                    time.sleep(seconds)
+                with TRACER.span("llmr.poll_wait"):
+                    if wake is not None:
+                        wake.wait(timeout=seconds)
+                        wake.clear()
+                    else:
+                        time.sleep(seconds)
 
             tick = 1e-4            # adaptive poll tick: tight while the
             while slots:           # wave is fresh, backing off toward 2ms
@@ -478,13 +480,14 @@ class LLMapReduce:
             report.waves = state["wi"]
 
             result = [outs[i] for i in range(report.waves)]
-            if reduce_fn is not None:
-                t = Timer()
-                flat = _concat_waves(result)
-                result = reduce_fn(flat)
-                report.t_reduce = t.lap()
-            else:
-                result = _concat_waves(result)
+            with TRACER.span("llmr.assemble"):
+                if reduce_fn is not None:
+                    t = Timer()
+                    flat = _concat_waves(result)
+                    result = reduce_fn(flat)
+                    report.t_reduce = t.lap()
+                else:
+                    result = _concat_waves(result)
         finally:
             # finish (and pop) the root even on failure so the thread's
             # current-span stack never leaks into the caller's next call

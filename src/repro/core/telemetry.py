@@ -205,7 +205,8 @@ def table(records: List[LaunchRecord], title: Optional[str] = None) -> str:
 # The serving-side analogue of ``LaunchRecord``: one finished request's cost
 # split. TTFT (time to first token, from ENQUEUE — queue wait included, the
 # user feels the queue) is the serving face of the launch tree's
-# ``t_first_result``; TPOT (time per output token after the first) is the
+# ``t_first_result``; its queue part (enqueue -> the request takes a slot,
+# before its prefill dispatch) is ``queue_s``; TPOT (time per output token after the first) is the
 # steady-state decode rate. ``class_summary``/``slo_attainment`` aggregate
 # per priority class against the same ``target_first_result_s`` SLO the
 # ``WaveController`` consumes on the launch side.
@@ -220,14 +221,15 @@ class RequestRecord:
     preemptions: int = 0
     finish: str = "length"       # length | capacity | pool_exhausted |
     #                              rejected_over_capacity
+    queue_s: float = 0.0         # enqueue -> admission (0 if never admitted)
 
     def row(self) -> str:
-        return (f"{self.rid},{self.priority},{self.ttft_s:.4f},"
-                f"{self.tpot_s:.5f},{self.n_tokens},{self.preemptions},"
-                f"{self.finish}")
+        return (f"{self.rid},{self.priority},{self.queue_s:.4f},"
+                f"{self.ttft_s:.4f},{self.tpot_s:.5f},{self.n_tokens},"
+                f"{self.preemptions},{self.finish}")
 
 
-SERVE_HEADER = "rid,class,ttft_s,tpot_s,tokens,preemptions,finish"
+SERVE_HEADER = "rid,class,queue_s,ttft_s,tpot_s,tokens,preemptions,finish"
 
 
 def serve_table(records: List[RequestRecord],

@@ -32,6 +32,26 @@ Enable the pillars with :func:`enable_observability` (pass
 ``sampling=True`` to also start the background sampler);
 ``python -m repro.obs.report trace.json`` renders a captured trace and
 ``--metrics`` renders a metrics snapshot.
+
+Program spans beside the device ops: turn the tracer on around a
+profiler capture,
+
+    enable_observability(tracing=True, metrics=False)
+    with jax.profiler.trace(log_dir):
+        ...                       # launches, engine steps
+    disable_observability()
+
+and every span also lands in the profiler trace as ``repro.<span name>``
+on the host lane of the thread that ran it, on the clock of the device
+ops, so an idle stretch of the device shows the span it fell in. The
+launch path: ``llmr.map_reduce`` over ``llmr.load`` (the wave loader),
+``dispatch`` (``backend.compile_lookup``, ``backend.enqueue``),
+``llmr.poll_wait`` (each pause between readiness polls), ``harvest``
+(``backend.result``) and ``llmr.assemble`` (wave concat and reduce). The
+serve engine: ``serve.run`` over ``serve.admit`` (``serve.prefill``),
+``serve.pre_step``, ``serve.decode`` and ``serve.emit``. Each request's
+wait for a slot is ``RequestRecord.queue_s`` (enqueue to admission,
+before its prefill dispatch).
 """
 from typing import Optional
 

@@ -19,6 +19,16 @@ Export: :meth:`Tracer.chrome_trace` produces Chrome-trace/Perfetto JSON
 ("traceEvents" with complete events + thread-name metadata);
 :func:`flame_summary` renders the parent/child tree as indented text.
 ``python -m repro.obs.report trace.json`` does both from a saved file.
+
+On the device trace's clock: every span :meth:`Tracer.start` opens also
+starts a ``jax.profiler.TraceAnnotation`` named ``repro.<span name>``,
+stopped by the span's finish. Under a ``jax.profiler.trace(dir)``
+capture the span therefore lands on the host lane of the profiler trace,
+beside the device ops it waited on, so an idle gap of the device reads
+as the program span it fell in. A span finished on another thread than
+the one that opened it lands on the finishing thread's lane. Deferred
+spans (:meth:`Tracer.defer`, :meth:`Tracer.defer_result`) are built from
+tuples after the fact and stay ring-only.
 """
 from __future__ import annotations
 
@@ -30,10 +40,15 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span", "Tracer", "TRACER", "new_span_id", "new_trace_id",
     "make_span", "flame_summary",
 ]
+
+#: profiler-trace name of a span: ``repro.<span name>``
+ANNOTATION_PREFIX = "repro."
 
 _ids = itertools.count(1)
 
@@ -68,7 +83,7 @@ class Span:
     """In-flight span; finished spans live in the ring as plain dicts."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "_pc0",
-                 "where", "attrs", "_tracer")
+                 "where", "attrs", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str], where: str,
@@ -82,12 +97,17 @@ class Span:
         self.attrs = dict(attrs) if attrs else {}
         self.t0 = time.time()
         self._pc0 = time.perf_counter()
+        # the profiler event starts here (a no-op outside a capture)
+        self._ann = TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def context(self) -> Tuple[str, str]:
         """Wire form: ``(trace_id, span_id)`` — what frames carry."""
         return (self.trace_id, self.span_id)
 
     def finish(self, **attrs: Any) -> dict:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
         if attrs:
             self.attrs.update(attrs)
         rec = make_span(self.name, self.trace_id, self.parent_id, self.t0,
@@ -196,6 +216,16 @@ class Tracer:
         """Context manager; the span becomes this thread's current span."""
         return _SpanCtx(self.start(name, parent, where, attrs, push=True)
                         if self.enabled else None)
+
+    def child(self, name: str, where: str = "",
+              attrs: Optional[dict] = None) -> _SpanCtx:
+        """Like :meth:`span`, but only under this thread's current span:
+        a thread with none records nothing, so a node worker thread that
+        runs a shard through a backend adds no roots of its own (its
+        work reaches the tree as the deferred ``node.*`` spans)."""
+        if not self.enabled or self.current() is None:
+            return _SpanCtx(None)
+        return _SpanCtx(self.start(name, None, where, attrs, push=True))
 
     # -- recording / ingest ----------------------------------------------
     # deque.append/extend/popleft are atomic under the GIL: the recording

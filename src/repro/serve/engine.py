@@ -69,6 +69,7 @@ from repro.core.telemetry import RequestRecord, class_summary, slo_attainment
 from repro.kernels.ops import on_tpu
 from repro.obs import flight as _flight
 from repro.obs import metrics as _obs
+from repro.obs.trace import TRACER
 from repro.models.lm import (cache_init, decode_step, paged_cache_init,
                              paged_clear, paged_copy, paged_decode_step,
                              paged_prefill, prefill)
@@ -88,7 +89,9 @@ class Request:                            # a ticket, not a value
     finish_reason: Optional[str] = None
     # telemetry stamps (perf_counter seconds); budget = max_new after the
     # capacity clamp. Reset by preemption: a preempted request restarts.
+    # t_admit: the request took a slot, before its prefill was dispatched
     t_enqueue: float = 0.0
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
     preemptions: int = 0
@@ -99,10 +102,13 @@ class Request:                            # a ticket, not a value
         ttft = (self.t_first - self.t_enqueue) if self.t_first else 0.0
         tpot = ((self.t_done - self.t_first) / (n - 1)
                 if n > 1 and self.t_done and self.t_first else 0.0)
+        queue = (self.t_admit - self.t_enqueue
+                 if self.t_admit is not None else 0.0)
         return RequestRecord(rid=self.rid, priority=self.priority,
                              ttft_s=ttft, tpot_s=tpot, n_tokens=n,
                              preemptions=self.preemptions,
-                             finish=self.finish_reason or "length")
+                             finish=self.finish_reason or "length",
+                             queue_s=queue)
 
 
 class _EngineBase:
@@ -119,7 +125,8 @@ class _EngineBase:
             else AdmissionScheduler()
         # called as logit_sink(request, logits_row) for every row of logits
         # a request gets (its prefill, then each decode step): lets a
-        # caller check one attention path against another on live traffic
+        # caller check one attention path against another on live traffic.
+        # Rows are sliced off the device only while a sink is set.
         self.logit_sink: Optional[Callable[[Request, jax.Array], None]] = None
         self.tokens = jnp.zeros((slots, 1), jnp.int32)
         self.pos = jnp.zeros((slots, 1), jnp.int32)
@@ -179,9 +186,9 @@ class _EngineBase:
         """Run one decode step -> (next token per slot, logits)."""
         raise NotImplementedError
 
-    def _emit(self, req: Request, row: jax.Array) -> None:
+    def _emit(self, req: Request, logits: jax.Array, *index: int) -> None:
         if self.logit_sink is not None:
-            self.logit_sink(req, row)
+            self.logit_sink(req, logits[index])
 
     def _release_slot(self, i: int) -> None:
         self.active[i] = None
@@ -198,37 +205,44 @@ class _EngineBase:
         if _obs.REGISTRY.enabled and rec.n_tokens > 0:
             self._m_ttft.observe(rec.ttft_s)
             self._m_tpot.observe(rec.tpot_s)
-            now = time.time()
-            _obs.REGISTRY.series_append("serve.ttft_s", now, rec.ttft_s)
-            _obs.REGISTRY.series_append("serve.tpot_s", now, rec.tpot_s)
+            _obs.REGISTRY.series_append("serve.ttft_s", time.time(),
+                                        rec.ttft_s)
         self._release_slot(i)
 
     def step(self) -> None:
         """One batched decode step across all slots."""
-        nxt, logits = self._step_executable()
-        now = time.perf_counter()
-        self.stats["steps"] += 1
-        for i, req in enumerate(self.active):
-            if req is None or i in self._stalled:
-                continue
-            self._emit(req, logits[i, 0])
-            req.out.append(int(nxt[i]))
-            self.stats["decoded"] += 1
-            if req.t_first is None:
-                req.t_first = now
-            if len(req.out) >= req.budget:
-                self._finish(i)
+        with TRACER.span("serve.decode"):
+            nxt, logits = self._step_executable()
+        with TRACER.span("serve.emit"):
+            now = time.perf_counter()
+            self.stats["steps"] += 1
+            for i, req in enumerate(self.active):
+                if req is None or i in self._stalled:
+                    continue
+                self._emit(req, logits, i, 0)
+                req.out.append(int(nxt[i]))
+                self.stats["decoded"] += 1
+                if req.t_first is None:
+                    req.t_first = now
+                if len(req.out) >= req.budget:
+                    self._finish(i)
 
     def run(self, requests: List[Request], max_steps: int = 10_000) -> dict:
+        with TRACER.span("serve.run"):
+            return self._run(requests, max_steps)
+
+    def _run(self, requests: List[Request], max_steps: int) -> dict:
         t0 = time.perf_counter()
         for r in requests:
             self.scheduler.enqueue(r)
         while ((self.scheduler.has_pending()
                 or any(a is not None for a in self.active))
                and self.stats["steps"] < max_steps):
-            admitted = self._admit()
+            with TRACER.span("serve.admit"):
+                admitted = self._admit()
             if any(a is not None for a in self.active):
-                self._pre_step()
+                with TRACER.span("serve.pre_step"):
+                    self._pre_step()
                 self.step()
             elif not admitted and self.scheduler.has_pending():
                 # idle engine that cannot place the head request: fail it
@@ -244,9 +258,6 @@ class _EngineBase:
         if slo is not None:
             att = slo_attainment(self.records, slo)
             self.stats["slo_attainment"] = att
-            if _obs.REGISTRY.enabled:
-                _obs.REGISTRY.series_append("serve.slo_attainment",
-                                            time.time(), att)
             if att < _flight.RECORDER.slo_min:
                 _flight.RECORDER.trigger("slo_breach", attainment=att,
                                          target_first_result_s=slo)
@@ -304,6 +315,7 @@ class ServeEngine(_EngineBase):
             return False                      # rejected: over capacity
         for i, a in enumerate(self.active):
             if a is None:
+                req.t_admit = time.perf_counter()
                 logits, caches = self._prefill(
                     jnp.asarray(req.prompt, jnp.int32)[None])
                 self.stats["prefill_dispatches"] += 1
@@ -315,7 +327,7 @@ class ServeEngine(_EngineBase):
                 # the scan-stack axis)
                 self.caches = jax.tree_util.tree_map(
                     lambda d, s: jax.vmap(put)(d, s), self.caches, caches)
-                self._emit(req, logits[0, -1])
+                self._emit(req, logits, 0, -1)
                 tok = int(jnp.argmax(logits[0, -1]))
                 req.out.append(tok)
                 req.t_first = time.perf_counter()
@@ -572,7 +584,7 @@ class PagedServeEngine(_EngineBase):
         pages, requeue it at the front of its class, restart-on-readmit."""
         req = self.active[i]
         req.out.clear()
-        req.t_first = None
+        req.t_admit = req.t_first = None
         req.preemptions += 1
         self.stats["preemptions"] += 1
         if _obs.REGISTRY.enabled:
@@ -695,7 +707,8 @@ class PagedServeEngine(_EngineBase):
         for req in reversed(leftover):       # restore original queue order
             self.scheduler.requeue_front(req)
         if placed:
-            self._prefill_commit(placed)
+            with TRACER.span("serve.prefill"):
+                self._prefill_commit(placed)
         return len(placed)
 
     def _admit_warm(self, req: Request, L: int, entry: dict) -> bool:
@@ -726,37 +739,39 @@ class PagedServeEngine(_EngineBase):
             if freed:
                 self.kv = paged_clear(self.kv, freed)
             return False
-        S_suf = S - suffix_start
-        S_pad = min(bucket_len(S_suf), self.pool.vcap)
-        toks = np.zeros((1, S_pad), np.int64)
-        toks[0, :S_suf] = req.prompt[suffix_start:]
-        trows = self.pool.table_array()[slot][None]
-        exe = self._warm_exec(S_pad)
-        logits, self.kv = exe(self.params, self.kv,
-                              jnp.asarray(trows, jnp.int32),
-                              jnp.asarray(toks, jnp.int32),
-                              jnp.asarray([S_suf], jnp.int32),
-                              jnp.asarray([slot], jnp.int32),
-                              jnp.asarray([suffix_start], jnp.int32))
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_rows"] += S_suf
-        self.stats["prefix_hits"] += 1
-        if _obs.REGISTRY.enabled:
-            self._m_phit.inc()
-        self._emit(req, logits[0, -1])
-        tok = int(jnp.argmax(logits[0, -1]))
-        req.out.append(tok)
-        req.t_first = time.perf_counter()
-        self.tokens = self.tokens.at[slot, 0].set(tok)
-        self.pos = self.pos.at[slot, 0].set(S)
-        self.active[slot] = req
-        self._admit_order += 1
-        self._admit_seq[slot] = self._admit_order
-        self.stats["admitted"] += 1
-        self._tables_dirty = True
-        self._register(slot, req)  # a warm prompt seeds longer prefixes too
-        if len(req.out) >= req.budget:
-            self._finish(slot)
+        req.t_admit = time.perf_counter()
+        with TRACER.span("serve.prefill"):
+            S_suf = S - suffix_start
+            S_pad = min(bucket_len(S_suf), self.pool.vcap)
+            toks = np.zeros((1, S_pad), np.int64)
+            toks[0, :S_suf] = req.prompt[suffix_start:]
+            trows = self.pool.table_array()[slot][None]
+            exe = self._warm_exec(S_pad)
+            logits, self.kv = exe(self.params, self.kv,
+                                  jnp.asarray(trows, jnp.int32),
+                                  jnp.asarray(toks, jnp.int32),
+                                  jnp.asarray([S_suf], jnp.int32),
+                                  jnp.asarray([slot], jnp.int32),
+                                  jnp.asarray([suffix_start], jnp.int32))
+            self.stats["prefill_dispatches"] += 1
+            self.stats["prefill_rows"] += S_suf
+            self.stats["prefix_hits"] += 1
+            if _obs.REGISTRY.enabled:
+                self._m_phit.inc()
+            self._emit(req, logits, 0, -1)
+            tok = int(jnp.argmax(logits[0, -1]))
+            req.out.append(tok)
+            req.t_first = time.perf_counter()
+            self.tokens = self.tokens.at[slot, 0].set(tok)
+            self.pos = self.pos.at[slot, 0].set(S)
+            self.active[slot] = req
+            self._admit_order += 1
+            self._admit_seq[slot] = self._admit_order
+            self.stats["admitted"] += 1
+            self._tables_dirty = True
+            self._register(slot, req)  # a warm prompt seeds longer ones too
+            if len(req.out) >= req.budget:
+                self._finish(slot)
         return True
 
     def _prefill_commit(self, placed: List[Tuple[int, Request]]) -> None:
@@ -773,9 +788,11 @@ class PagedServeEngine(_EngineBase):
         toks = np.zeros((B, S), np.int64)
         lens = np.zeros((B,), np.int64)
         trows = np.full((B, self.pool.pages_per_slot), -1, np.int32)
-        sids = np.full((B,), self.slots, np.int64)      # OOB = dummy row
+        sids = np.full((B,), self.slots, np.int64)  # OOB = dummy row
         table = self.pool.table_array()
+        t_admit = time.perf_counter()
         for r, (slot, req) in enumerate(placed):
+            req.t_admit = t_admit
             n = len(req.prompt)
             toks[r, :n] = req.prompt
             lens[r] = n
@@ -792,7 +809,7 @@ class PagedServeEngine(_EngineBase):
         first = np.asarray(jnp.argmax(logits[:, -1], -1), np.int64)
         now = time.perf_counter()
         for r, (slot, req) in enumerate(placed):
-            self._emit(req, logits[r, -1])
+            self._emit(req, logits, r, -1)
             tok = int(first[r])
             req.out.append(tok)
             req.t_first = now
@@ -802,7 +819,8 @@ class PagedServeEngine(_EngineBase):
             self._admit_order += 1
             self._admit_seq[slot] = self._admit_order
             self.stats["admitted"] += 1
-            if self._prefix_ok and len(req.prompt) >= self.prefix_min_tokens:
+            if (self._prefix_ok
+                    and len(req.prompt) >= self.prefix_min_tokens):
                 self.stats["prefix_misses"] += 1   # served cold
                 if _obs.REGISTRY.enabled:
                     self._m_pmiss.inc()

@@ -462,3 +462,17 @@ def test_observability_off_adds_no_spans_or_metrics(tmp_path):
     assert TRACER.spans() == []
     assert REGISTRY.snapshot().get("pump.frames_out", 0) == 0
     assert "tc" not in rep.extra          # no trace context on the wire
+
+
+def test_child_span_needs_a_current_span(obs):
+    """``child`` nests under the thread's current span and records
+    nothing on a thread that has none (a node worker running a shard)."""
+    with TRACER.child("orphan"):
+        pass
+    with TRACER.span("root"):
+        with TRACER.child("leaf") as leaf:
+            assert TRACER.current() is leaf
+    spans = {s["name"]: s for s in TRACER.spans()}
+    assert set(spans) == {"root", "leaf"}
+    assert spans["leaf"]["parent_id"] == spans["root"]["span_id"]
+    assert TRACER.current() is None

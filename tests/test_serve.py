@@ -296,3 +296,58 @@ def test_request_records_and_class_summary(setup):
         assert rec.ttft_s > 0 and rec.n_tokens == 4
     assert set(stats["classes"]) == {"interactive", "batch"}
     assert stats["classes"]["interactive"]["n"] == 4
+
+
+def _stamps_in_order(req):
+    assert req.t_enqueue <= req.t_admit <= req.t_first <= req.t_done
+    rec = req.record()
+    assert rec.queue_s == pytest.approx(req.t_admit - req.t_enqueue)
+    assert 0.0 <= rec.queue_s <= rec.ttft_s
+
+
+@pytest.mark.parametrize("path", ["cold", "warm", "preempted"])
+def test_admission_stamp_orders_the_request_stamps(setup, path):
+    """``t_admit`` is stamped when a request takes a slot, before its
+    prefill is dispatched, on a cold and a warm (prefix-hit) admission;
+    preemption clears it with ``t_first`` and re-admission stamps it
+    anew. ``RequestRecord.queue_s`` is the stamp less ``t_enqueue``."""
+    from repro.core.telemetry import serve_table
+    cfg, params = setup
+    rng = np.random.default_rng(11)
+    if path == "preempted":
+        b1, b2 = (Request(rid=i, prompt=rng.integers(0, cfg.vocab, size=8),
+                          max_new=8, priority="batch") for i in (0, 1))
+        eng = PagedServeEngine(cfg, params, slots=2, page_size=4,
+                               pages_per_slot=4, pool_pages=4)
+        eng.scheduler.enqueue(b1)
+        eng.scheduler.enqueue(b2)
+        assert eng._admit() == 2
+        first_admit = b2.t_admit
+        assert first_admit is not None
+        eng.scheduler.enqueue(Request(rid=2, prompt=rng.integers(
+            0, cfg.vocab, size=8), max_new=4))
+        assert eng._admit() == 1                    # b2 preempted
+        assert b2.preemptions == 1
+        assert b2.t_admit is None and b2.t_first is None
+        eng.run([], max_steps=400)
+        assert b2.t_admit > first_admit
+        reqs = [b1, b2]
+    else:
+        eng = PagedServeEngine(cfg, params, slots=2, page_size=4,
+                               pages_per_slot=8, pool_pages=16,
+                               prefix_sharing=True)
+        p1 = rng.integers(1, cfg.vocab, 16)
+        reqs = [Request(rid=0, prompt=p1, max_new=4)]
+        if path == "warm":
+            eng.run(reqs)
+            reqs = [Request(rid=1, prompt=np.concatenate(
+                [p1, rng.integers(1, cfg.vocab, 4)]), max_new=4)]
+        eng.run(reqs)
+        assert eng.stats["prefix_hits"] == (path == "warm")
+    for r in reqs:
+        assert r.done
+        _stamps_in_order(r)
+    table = serve_table(eng.records).splitlines()
+    assert table[0].split(",")[2] == "queue_s"
+    assert float(table[-1].split(",")[2]) == pytest.approx(
+        eng.records[-1].queue_s, abs=1e-4)
