@@ -413,7 +413,8 @@ class PagedServeEngine(_EngineBase):
             pool_pages = slots * pages_per_slot
         self.pool = PagePool(pool_pages, page_size, slots, pages_per_slot)
         self.kv = paged_cache_init(cfg, slots, pool_pages, page_size)
-        self.tables = jnp.asarray(self.pool.table_array())
+        self._tables_host = self.pool.table_array()
+        self.tables = jnp.asarray(self._tables_host)
         self._tables_dirty = False
         self.batched_prefill = batched_prefill
         if kernel == "auto":
@@ -450,10 +451,14 @@ class PagedServeEngine(_EngineBase):
         self._warm_by_len: dict = {}           # S_pad  -> AOT executable
         self.stats.update({"prefix_hits": 0, "prefix_misses": 0,
                            "prefix_registered": 0, "cow_pages": 0,
-                           "prefill_rows": 0, "kv_bytes_avoided": 0})
+                           "prefill_rows": 0, "kv_bytes_avoided": 0,
+                           "kernel_pages_read": 0,
+                           "kernel_pages_skipped": 0})
         self._m_phit = _obs.counter("serve.prefix.hits")
         self._m_pmiss = _obs.counter("serve.prefix.misses")
         self._m_bytes = _obs.counter("serve.kernel.bytes_avoided")
+        self._m_read = _obs.counter("serve.kernel.pages_read")
+        self._m_skip = _obs.counter("serve.kernel.pages_skipped")
 
     def _kv_geometry(self) -> Tuple[int, int]:
         """(bytes of dense per-slot views the gather path materializes per
@@ -888,26 +893,34 @@ class PagedServeEngine(_EngineBase):
 
     def _step_executable(self):
         if self._tables_dirty:
-            self.tables = jnp.asarray(self.pool.table_array())
+            self._tables_host = self.pool.table_array()
+            self.tables = jnp.asarray(self._tables_host)
             self._tables_dirty = False
         keep = np.ones((self.slots,), bool)
-        tbl = self.tables
+        tbl, host = self.tables, self._tables_host
         if self._stalled:
             keep[list(self._stalled)] = False
             # a stalled slot must not write: a page-less stall drops its
             # KV write anyway, but a COW-stall's write would land in a
             # SHARED page — blank the whole row (its output is discarded
             # and the identical step is retried with the real table)
-            masked = self.pool.table_array()
-            masked[list(self._stalled)] = -1
-            tbl = jnp.asarray(masked)
+            host = self.pool.table_array()
+            host[list(self._stalled)] = -1
+            tbl = jnp.asarray(host)
         self._live = jnp.asarray(keep)
         logits, self.kv = self._step(self.params, self.kv, tbl,
                                      self.tokens, self.pos, self._live)
         if self.kernel == "pallas":
+            # the decode kernel copies live table entries, skips the rest
+            read = int(np.count_nonzero(host >= 0))
+            skipped = host.size - read
             self.stats["kv_bytes_avoided"] += self._dense_view_bytes
+            self.stats["kernel_pages_read"] += read
+            self.stats["kernel_pages_skipped"] += skipped
             if _obs.REGISTRY.enabled:
                 self._m_bytes.inc(self._dense_view_bytes)
+                self._m_read.inc(read)
+                self._m_skip.inc(skipped)
         nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
         if self._stalled:
             # stalled slots hold position: same token, same pos, identical

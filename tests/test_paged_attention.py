@@ -5,8 +5,9 @@ Three layers of evidence, cheapest first:
 * kernel vs oracle — ``kernels.paged_attention`` (interpret=True) against
   the dense ``kernels.ref.paged_attention_ref`` across page sizes,
   GQA/MQA, windows, softcap, the MLA two-component form, fragmented and
-  permuted page tables, ragged/padded query batches, and full-pool
-  occupancy (allclose: same math, different reduction order);
+  permuted page tables, ragged/padded query batches, full-pool
+  occupancy, and the decode form's ragged, holed and dead page blocks
+  (allclose: same math, different reduction order);
 * lm-level bit equality — ``paged_prefill``/``paged_decode_step`` with
   ``kernel="pallas"`` produce the SAME greedy tokens as the
   ``kernel="gather"`` dense-materialize baseline on bounded decode
@@ -123,6 +124,49 @@ def test_kernel_matches_ref_partial_tables_full_pool():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     assert np.all(np.asarray(out)[1] == 0.0)            # dead slot -> zeros
+
+
+# Decode form (S == 1): the grid walks (slot, page block); one block covers
+# every kv head and DECODE_BLOCK_BYTES // (K*ps*Dk*itemsize) pages. The
+# shapes below (float32, 128-lane minor dims) make that 4 pages (16 for
+# the MLA case), so blocks end ragged and holes fall inside a block.
+def _decode_case(case):
+    kw = dict(B=2, H=8, K=4, Dk=512, Dv=512, ps=8, npps=10, P=24)
+    if case == "npps_not_multiple":
+        args, extra = _mk(10, **kw), {}
+    elif case == "hole_before_live":
+        args, extra = list(_mk(11, filled=[7, 9], **kw)), {}
+        tables = np.array(args[4])
+        tables[0, 1] = -1                       # slot 0: a hole in block 0
+        tables[1, [4, 5, 7]] = -1               # slot 1: block 1 keeps page 6
+        args[4] = jnp.asarray(tables)
+    elif case == "every_slot_dead":
+        args, extra = _mk(12, filled=[0, 0], **kw), {}
+    elif case == "mla_k1_two_component":
+        kw.update(H=4, K=1, npps=20, P=40)
+        args = _mk(13, q2dim=64, **kw)
+        extra = dict(scale=1.0 / math.sqrt(512 + 64))
+    elif case == "window_softcap":
+        args, extra = _mk(14, filled=[10, 6], **kw), dict(window=37,
+                                                          softcap=30.0)
+    elif case == "live_ends_mid_block":
+        args, extra = _mk(15, filled=[6, 3], **kw), {}
+    return args, extra, kw
+
+
+@pytest.mark.parametrize("case", [
+    "npps_not_multiple", "hole_before_live", "every_slot_dead",
+    "mla_k1_two_component", "window_softcap", "live_ends_mid_block"])
+def test_decode_form_matches_ref(case):
+    from repro.kernels.paged_attention import DECODE_BLOCK_BYTES
+    args, extra, kw = _decode_case(case)
+    ppb = DECODE_BLOCK_BYTES // (kw["K"] * kw["ps"] * kw["Dk"] * 4)
+    assert 1 < ppb < kw["npps"] and kw["npps"] % ppb     # blocks end ragged
+    out, ref = _both(args, **extra)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    dead = np.all(np.asarray(args[4]) < 0, axis=1)
+    assert np.all(np.asarray(out)[dead] == 0.0)          # dead slot -> zeros
 
 
 # ---------------------------------------------------------------------------
@@ -430,3 +474,38 @@ def test_engine_tokens_identical_dense_gather_pallas(qwen):
                     top2 = np.sort(b)[-2:]
                     assert top2[1] - top2[0] <= tol, (name, r.rid, step)
                     break
+
+
+def test_engine_counts_kernel_pages_read_and_skipped(qwen):
+    """``serve.kernel.pages_read`` is the live table entries handed to the
+    decode kernel, summed over steps; read + skipped covers every entry of
+    every step's table."""
+    from repro.obs import REGISTRY, disable_observability, enable_observability
+    cfg, params = qwen
+    r = np.random.default_rng(10)
+    reqs = [Request(rid=i, prompt=r.integers(1, cfg.vocab, 5 + 4 * i),
+                    max_new=4 + 3 * i) for i in range(3)]
+    eng = PagedServeEngine(cfg, params, slots=2, page_size=4,
+                           pages_per_slot=6, kernel="pallas")
+    live = []
+    step = eng._step
+
+    def counting_step(p, kv, tables, *rest):
+        live.append(int(np.count_nonzero(np.asarray(tables) >= 0)))
+        return step(p, kv, tables, *rest)
+
+    eng._step = counting_step
+    REGISTRY.clear()
+    enable_observability()
+    try:
+        stats = eng.run(reqs, max_steps=500)
+        read = REGISTRY.counter("serve.kernel.pages_read").value
+        skipped = REGISTRY.counter("serve.kernel.pages_skipped").value
+    finally:
+        disable_observability()
+        REGISTRY.clear()
+    assert all(q.done for q in reqs)
+    assert len(live) == stats["steps"] > 0
+    assert read == stats["kernel_pages_read"] == sum(live) > 0
+    assert skipped == stats["kernel_pages_skipped"] > 0
+    assert read + skipped == stats["steps"] * eng.slots * eng.pool.pages_per_slot

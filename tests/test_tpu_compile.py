@@ -50,16 +50,16 @@ def no_persistent_cache():
         cc.reset_cache()
 
 
-def _compile(one_chip, S, block_q):
+def _compile(one_chip, S, block_q, slots=SLOTS, pool_pages=POOL_PAGES):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    args = (sds((SLOTS, S, H, D), jnp.bfloat16),
-            sds((POOL_PAGES, K, PS, D), jnp.bfloat16),
-            sds((POOL_PAGES, K, PS, D), jnp.bfloat16),
-            sds((POOL_PAGES, PS), jnp.int32),
-            sds((SLOTS, NPPS), jnp.int32),
-            sds((SLOTS, S), jnp.int32))
+    args = (sds((slots, S, H, D), jnp.bfloat16),
+            sds((pool_pages, K, PS, D), jnp.bfloat16),
+            sds((pool_pages, K, PS, D), jnp.bfloat16),
+            sds((pool_pages, PS), jnp.int32),
+            sds((slots, NPPS), jnp.int32),
+            sds((slots, S), jnp.int32))
     fn = jax.jit(lambda q, k, v, kp, t, qp: paged_attention(
         q, k, v, kp, t, qp, block_q=block_q))
     return fn.lower(*args).compile()
@@ -71,3 +71,12 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
                                           S, block_q):
     compiled = _compile(one_chip, S, block_q)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_form_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The decode form (S == 1: a grid of slot x page block, pages copied
+    by hand) at the qwen3-14b-stage engine's sizes: 16 slots, a pool of
+    2,048 pages."""
+    compiled = _compile(one_chip, 1, 128, slots=16, pool_pages=2048)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
